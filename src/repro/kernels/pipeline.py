@@ -39,9 +39,10 @@ The pipeline contract, common to all three builders:
   length ``rows + 2*halo``; the wrapper pads, so every chunk's fetch
   window ``[c*block_rows, c*block_rows + block_rows + 2*halo)`` is in
   bounds without clamping) and fetches overlapping windows while writing
-  disjoint ``block_rows``-sized outputs.  The compute callback receives
-  the fetched tile plus the chunk's global row offset so it can mask
-  physical-boundary rows.
+  disjoint ``block_rows``-sized outputs.  Windows and trailing dims are
+  rounded up to whole (8, 128) tiles, which Mosaic's DMA requires.  The
+  compute callback receives the fetched tile plus the chunk's global row
+  offset so it can mask physical-boundary rows.
 
 Measuring one kernel at ``num_stages=1`` and ``>=2`` and placing the
 runtime between the two bounds yields the machine's overlap coefficient —
@@ -169,7 +170,9 @@ def _map_pipeline_kernel(compute, n_scalars: int, n_in: int, *,
 
 def _reduce_pipeline_kernel(compute, n_in: int, *, n_chunks: int,
                             stages: int, block_rows: int, dtype, acc_dtype):
-    """Reduction pipeline: out[0,0] = sum_chunks sum(compute(*blocks)).
+    """Reduction pipeline: ``out[0, l] = sum over chunks and rows of
+    compute(*blocks)[:, l]`` — one partial sum per lane, which the caller
+    reduces to a scalar (Mosaic stores vectors, not scalars, to VMEM).
 
     The accumulation order is chunk-sequential and independent of
     ``num_stages``, so results are bit-identical across pipeline depths.
@@ -204,10 +207,11 @@ def _reduce_pipeline_kernel(compute, n_in: int, *, n_chunks: int,
                     in_dma(slot, chunk, j).wait()
 
                 blocks = [in_scr[j, slot] for j in range(n_in)]
-                return acc + jnp.sum(compute(*blocks).astype(acc_dtype))
+                return acc + jnp.sum(compute(*blocks).astype(acc_dtype),
+                                     axis=0, keepdims=True)
 
-            acc0 = jnp.zeros((), acc_dtype)
-            out_ref[0, 0] = jax.lax.fori_loop(0, n_chunks, loop, acc0)
+            acc0 = jnp.zeros((1, LANES), acc_dtype)
+            out_ref[...] = jax.lax.fori_loop(0, n_chunks, loop, acc0)
 
         pl.run_scoped(
             body,
@@ -224,7 +228,7 @@ def _reduce_pipeline_kernel(compute, n_in: int, *, n_chunks: int,
 
 
 def _hbm_spec():
-    return pl.BlockSpec(memory_space=pltpu.ANY)
+    return pl.BlockSpec(memory_space=pl.ANY)
 
 
 def _smem_spec():
@@ -258,7 +262,8 @@ def map_pipeline_call(compute, n_scalars: int, n_in: int, *, x_shape, dtype,
 def reduce_pipeline_call(compute, n_in: int, *, x_shape, dtype,
                          num_stages: int = 2, block_rows: int = 64,
                          interpret: bool = False):
-    """Build a pipelined reduction ``pallas_call`` -> (1, 1) accumulator."""
+    """Build a pipelined reduction ``pallas_call`` -> (1, 128) per-lane
+    partial sums."""
     rows = x_shape[0]
     block_rows = _fit_block(rows, block_rows)
     n_chunks = rows // block_rows
@@ -271,7 +276,7 @@ def reduce_pipeline_call(compute, n_in: int, *, x_shape, dtype,
         kernel,
         in_specs=[_hbm_spec()] * n_in,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, 1), acc_dtype),
+        out_shape=jax.ShapeDtypeStruct((1, LANES), acc_dtype),
         interpret=interpret,
     )
 
@@ -282,19 +287,19 @@ def reduce_pipeline_call(compute, n_in: int, *, x_shape, dtype,
 
 
 def _halo_pipeline_kernel(compute, *, n_chunks: int, stages: int,
-                          block0: int, halo: int, in_rest: tuple,
+                          block0: int, halo: int, fetch: int, in_rest: tuple,
                           out_rest: tuple, dtype):
     """Overlapping-fetch pipeline: chunk ``c`` fetches the padded rows
-    ``[c*block0, c*block0 + block0 + 2*halo)`` and writes the disjoint
-    output rows ``[c*block0, (c+1)*block0)``.
+    ``[c*block0, c*block0 + fetch)`` and writes the disjoint output rows
+    ``[c*block0, (c+1)*block0)``.
 
-    ``compute(tile, g0)`` maps a ``(block0 + 2*halo, *in_rest)`` tile plus
-    the chunk's global first output row to a ``(block0, *out_rest)``
-    block.  Same warm-up/steady/drain schedule as the map pipeline;
-    overlapping *reads* are safe (each input row may be fetched by up to
-    two chunks) and writes never overlap.
+    ``compute(tile, g0)`` maps the ``(block0 + 2*halo, *in_rest)`` head of
+    the fetched rows plus the chunk's global first output row to a
+    ``(block0, *out_rest)`` block.  Same warm-up/steady/drain schedule as
+    the map pipeline; overlapping *reads* are safe (each input row may be
+    fetched by up to two chunks) and writes never overlap.
     """
-    fetch = block0 + 2 * halo
+    window = block0 + 2 * halo
     in_tail = (slice(None),) * len(in_rest)
     out_tail = (slice(None),) * len(out_rest)
 
@@ -331,8 +336,8 @@ def _halo_pipeline_kernel(compute, *, n_chunks: int, stages: int,
                 def _():
                     out_dma(slot, chunk - stages).wait()
 
-                out_scr[slot] = compute(in_scr[slot],
-                                        chunk * block0).astype(dtype)
+                tile = in_scr[(slot, pl.ds(0, window)) + in_tail]
+                out_scr[slot] = compute(tile, chunk * block0).astype(dtype)
                 out_dma(slot, chunk).start()
                 return ()
 
@@ -353,15 +358,26 @@ def _halo_pipeline_kernel(compute, *, n_chunks: int, stages: int,
     return kernel
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def halo_pipeline_call(compute, *, out_shape, in_shape, dtype, halo: int = 1,
                        num_stages: int = 2, block_rows: int = 8,
                        interpret: bool = False):
-    """Build a pipelined halo-exchange ``pallas_call`` (stencil engine).
+    """Build a pipelined halo-exchange stencil call.
 
     ``in_shape`` is the *pre-padded* input: axis 0 must be
     ``out_shape[0] + 2*halo`` (trailing dims are free — the caller decides
-    how much spatial padding the compute callback expects).  See the
-    module docstring for the full pipeline contract.
+    how much spatial padding the compute callback expects).  Mosaic DMAs
+    move whole (8, 128) tiles, so the returned callable zero-pads the input
+    further: the last dim to a multiple of 128, the second-to-last to a
+    multiple of 8 (for 2D arrays that is axis 0: each chunk's fetch window
+    is rounded up to whole tiles).  ``compute`` therefore sees tiles at
+    least as large as it asked for and slices what it needs.  On the chip
+    the output's trailing dims must already be whole tiles (a 2D output's
+    rows via ``block_rows``).  See the module docstring for the full
+    pipeline contract.
     """
     rows = out_shape[0]
     if in_shape[0] != rows + 2 * halo:
@@ -371,17 +387,26 @@ def halo_pipeline_call(compute, *, out_shape, in_shape, dtype, halo: int = 1,
     block0 = _fit_block(rows, block_rows)
     n_chunks = rows // block0
     stages = max(1, min(num_stages, n_chunks))
+    window = block0 + 2 * halo
+    fetch = _round_up(window, 8) if len(in_shape) == 2 else window
+    in_rest = list(in_shape[1:])
+    in_rest[-1] = _round_up(in_rest[-1], LANES)
+    if len(in_rest) >= 2:
+        in_rest[-2] = _round_up(in_rest[-2], 8)
+    pad = [(0, fetch - window)] + [(0, a - b) for a, b in
+                                   zip(in_rest, in_shape[1:])]
     kernel = _halo_pipeline_kernel(
         compute, n_chunks=n_chunks, stages=stages, block0=block0, halo=halo,
-        in_rest=tuple(in_shape[1:]), out_rest=tuple(out_shape[1:]),
+        fetch=fetch, in_rest=tuple(in_rest), out_rest=tuple(out_shape[1:]),
         dtype=dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         in_specs=[_hbm_spec()],
         out_specs=_hbm_spec(),
         out_shape=jax.ShapeDtypeStruct(tuple(out_shape), dtype),
         interpret=interpret,
     )
+    return lambda p: call(jnp.pad(p, pad))
 
 
 # ---------------------------------------------------------------------------

@@ -1,15 +1,11 @@
-"""Pallas TPU kernels for the Jacobi stencils (2D 5-point, 3D 7-point).
+"""Tile compute for the Jacobi stencils (2D 5-point, 3D 7-point).
 
-Two execution paths share the tile compute functions below:
-
-* ``jacobi2d_call`` / ``jacobi3d_call`` — single-step whole-array kernels
-  (the ``num_stages=None`` baseline: the padded array lands in VMEM in
-  one block, validation-sized problems only);
-* the halo pipeline — ``ops.py`` routes ``num_stages=k`` through
-  :func:`repro.kernels.pipeline.halo_pipeline_call`, which streams
-  overlapping ``(block_rows + 2, ...)`` tiles of the padded array
-  HBM->VMEM with ``k`` buffers and writes disjoint ``block_rows`` output
-  chunks (see the pipeline-contract docstring there).
+``ops.py`` runs these through the halo pipeline,
+:func:`repro.kernels.pipeline.halo_pipeline_call`, which streams
+overlapping ``(block_rows + 2, ...)`` tiles of the padded array HBM->VMEM
+with ``num_stages`` buffers and writes disjoint ``block_rows`` output
+chunks (see the pipeline-contract docstring there).  No path holds the
+whole array in VMEM: an 8192^2 f32 grid alone is twice the chip's 128 MiB.
 
 Inputs are pre-padded with one zero ring (``jnp.pad(a, 1)``) by the
 ``ops.py`` wrappers, so every tile fetch is in bounds without clamping;
@@ -17,24 +13,25 @@ the compute functions mask physical-boundary points back to the centre
 value (Dirichlet copy), which makes the result independent of the pad
 contents and bit-identical to ``ref.py``.
 
-Shapes are unconstrained in interpret mode; on a Mosaic backend the
-trailing dim is padded to the 128-lane tile by the compiler (stencil
-widths are arbitrary, unlike the lane-aligned stream kernels).
+Shapes are unconstrained in interpret mode.  On a Mosaic backend the
+pipeline pads the input to whole (8, 128) tiles, and the output's trailing
+dims must be whole tiles (2D: ``W`` a multiple of 128 and ``block_rows``
+of 8; 3D: ``H`` of 8 and ``W`` of 128).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-#: default pipeline chunk: 8 rows (2D) / 8 layers (3D) per DMA.
+#: default pipeline chunk per DMA: 8 rows (2D, one sublane tile) / 2
+#: layers (3D: 8 layers of a 256^3 f32 grid at depth 2 exceed the chip's
+#: scoped VMEM; 2 layers compile up to depth 3).
 BLOCK_ROWS = 8
+BLOCK_LAYERS = 2
 
 
 # ---------------------------------------------------------------------------
-# tile compute (shared by the whole-array kernels and the halo pipeline)
+# tile compute
 # ---------------------------------------------------------------------------
 
 
@@ -77,41 +74,3 @@ def seven_point_block(tile, g0, *, D: int, H: int, W: int,
     edge = ((ks == 0) | (ks == D - 1) | (js_i == 0) | (js_i == H - 1)
             | (is_i == 0) | (is_i == W - 1))
     return jnp.where(edge, c, val)
-
-
-# ---------------------------------------------------------------------------
-# whole-array pallas_call builders (num_stages=None baseline)
-# ---------------------------------------------------------------------------
-
-
-def _jacobi2d_kernel(p_ref, o_ref, *, H, W, c0, c1):
-    o_ref[...] = five_point_block(
-        p_ref[...], 0, H=H, W=W, c0=c0, c1=c1).astype(o_ref.dtype)
-
-
-def _jacobi3d_kernel(p_ref, o_ref, *, D, H, W, c0, c1):
-    o_ref[...] = seven_point_block(
-        p_ref[...], 0, D=D, H=H, W=W, c0=c0, c1=c1).astype(o_ref.dtype)
-
-
-def jacobi2d_call(shape, dtype, *, c0: float, c1: float,
-                  interpret: bool = False):
-    """Single-step kernel over the whole padded array: (H+2, W+2) -> (H, W)."""
-    H, W = shape
-    return pl.pallas_call(
-        functools.partial(_jacobi2d_kernel, H=H, W=W, c0=c0, c1=c1),
-        out_shape=jax.ShapeDtypeStruct((H, W), dtype),
-        interpret=interpret,
-    )
-
-
-def jacobi3d_call(shape, dtype, *, c0: float, c1: float,
-                  interpret: bool = False):
-    """Single-step kernel over the whole padded array: (D+2, H+2, W+2) ->
-    (D, H, W)."""
-    D, H, W = shape
-    return pl.pallas_call(
-        functools.partial(_jacobi3d_kernel, D=D, H=H, W=W, c0=c0, c1=c1),
-        out_shape=jax.ShapeDtypeStruct((D, H, W), dtype),
-        interpret=interpret,
-    )

@@ -3,11 +3,10 @@
 ``interpret`` defaults to True on CPU backends (this container); on a real
 TPU backend the same code lowers to Mosaic.
 
-``num_stages`` follows the stream-ops convention: ``None`` runs the
-single-step whole-array kernel (validation baseline); an integer routes
-through the halo-aware multi-buffered DMA pipeline
-(:func:`repro.kernels.pipeline.halo_pipeline_call`) with that many VMEM
-buffers per stream (1 = serial / no overlap, 2 = double buffering, ...).
+Every call runs the halo-aware multi-buffered DMA pipeline
+(:func:`repro.kernels.pipeline.halo_pipeline_call`) with ``num_stages``
+VMEM buffers per stream (1 = serial / no overlap, 2 = double buffering,
+...); ``None`` means the default depth, :data:`DEFAULT_STAGES`.
 Outputs are bit-identical across every ``num_stages`` setting and to the
 ``ref.py`` oracles — enforced by ``tests/test_stencil.py``.
 
@@ -27,6 +26,10 @@ from .. import pipeline as P
 from . import kernel as K
 
 
+#: pipeline depth for ``num_stages=None``
+DEFAULT_STAGES = 2
+
+
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -40,32 +43,27 @@ def jacobi2d(a, *, c0: float = 0.0, c1: float = 0.25, num_stages=None,
     interpret = _default_interpret() if interpret is None else interpret
     H, W = a.shape
     p = jnp.pad(a, 1)
-    if num_stages is None:
-        return K.jacobi2d_call((H, W), a.dtype, c0=c0, c1=c1,
-                               interpret=interpret)(p)
     compute = functools.partial(K.five_point_block, H=H, W=W, c0=c0, c1=c1)
     return P.halo_pipeline_call(
         compute, out_shape=(H, W), in_shape=p.shape, dtype=a.dtype, halo=1,
-        num_stages=num_stages, block_rows=block_rows, interpret=interpret,
+        num_stages=num_stages or DEFAULT_STAGES, block_rows=block_rows,
+        interpret=interpret,
     )(p)
 
 
 @functools.partial(jax.jit, static_argnames=("c0", "c1", "num_stages",
                                              "block_rows", "interpret"))
 def jacobi3d(a, *, c0: float = 0.0, c1: float = 1.0 / 6.0, num_stages=None,
-             block_rows: int = K.BLOCK_ROWS, interpret=None):
+             block_rows: int = K.BLOCK_LAYERS, interpret=None):
     """3D 7-point Jacobi sweep over (D, H, W); the pipeline chunks along
     the outermost (layer) axis with a one-layer halo."""
     interpret = _default_interpret() if interpret is None else interpret
     D, H, W = a.shape
     p = jnp.pad(a, 1)
-    if num_stages is None:
-        return K.jacobi3d_call((D, H, W), a.dtype, c0=c0, c1=c1,
-                               interpret=interpret)(p)
     compute = functools.partial(K.seven_point_block, D=D, H=H, W=W,
                                 c0=c0, c1=c1)
     return P.halo_pipeline_call(
         compute, out_shape=(D, H, W), in_shape=p.shape, dtype=a.dtype,
-        halo=1, num_stages=num_stages, block_rows=block_rows,
+        halo=1, num_stages=num_stages or DEFAULT_STAGES, block_rows=block_rows,
         interpret=interpret,
     )(p)

@@ -65,14 +65,15 @@ def _schoenauer_kernel(b_ref, c_ref, d_ref, a_ref):
 
 
 def _load_kernel(a_ref, o_ref):
-    """s += A[i] — block-level partial sums, reduced across the sequential
-    grid into a single (1, 1) output."""
+    """s += A[i] — per-lane partial sums of each block, accumulated across
+    the sequential grid into one (1, 128) row; the caller sums the lanes."""
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[0, 0] += jnp.sum(a_ref[...].astype(o_ref.dtype))
+    o_ref[...] += jnp.sum(a_ref[...].astype(o_ref.dtype), axis=0,
+                          keepdims=True)
 
 
 def _ddot_kernel(a_ref, b_ref, o_ref):
@@ -80,7 +81,8 @@ def _ddot_kernel(a_ref, b_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[0, 0] += jnp.sum((a_ref[...] * b_ref[...]).astype(o_ref.dtype))
+    o_ref[...] += jnp.sum((a_ref[...] * b_ref[...]).astype(o_ref.dtype),
+                          axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
@@ -96,7 +98,7 @@ def _compiler_params(semantics: str, interpret: bool):
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.TPUCompilerParams(dimension_semantics=(semantics,))
+    return pltpu.CompilerParams(dimension_semantics=(semantics,))
 
 
 def _streaming_call(body, n_in: int, *, scalar_first: bool, interpret: bool,
@@ -155,8 +157,8 @@ def _reduce_call(body, n_in, x_shape, dtype, *, block_rows, interpret):
         body,
         grid=_grid(rows, block_rows),
         in_specs=[_io_spec(block_rows) for _ in range(n_in)],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), acc_dtype),
+        out_specs=pl.BlockSpec((1, BLOCK_COLS), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, BLOCK_COLS), acc_dtype),
         interpret=interpret,
         compiler_params=_compiler_params("arbitrary", interpret),
     )

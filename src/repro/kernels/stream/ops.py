@@ -54,7 +54,7 @@ def load(a, *, block_rows=K.BLOCK_ROWS, interpret=None, num_stages=None):
     else:
         out = K.load_call(a2.shape, a2.dtype, block_rows=block_rows,
                           interpret=interpret)(a2)
-    return out[0, 0]
+    return jnp.sum(out)
 
 
 @functools.partial(jax.jit,
@@ -70,7 +70,7 @@ def ddot(a, b, *, block_rows=K.BLOCK_ROWS, interpret=None, num_stages=None):
     else:
         out = K.ddot_call(a2.shape, a2.dtype, block_rows=block_rows,
                           interpret=interpret)(a2, b2)
-    return out[0, 0]
+    return jnp.sum(out)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "block_rows",

@@ -15,13 +15,20 @@ Mesh semantics (TPU v5e pods):
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the models place activations by
+    ``with_sharding_constraint`` (GSPMD propagation), not by Explicit
+    sharding types, which are ``jax.make_mesh``'s default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, *, data: int | None = None,
@@ -31,8 +38,8 @@ def make_host_mesh(model: int = 1, *, data: int | None = None,
     data = data or max(n // model, 1)
     if multi_pod:
         assert data % 2 == 0
-        return jax.make_mesh((2, data // 2, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((2, data // 2, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict[str, int]:

@@ -2,8 +2,10 @@
 
 ``python -m repro.launch.serve --arch <id> --batch 4 --prompt-len 16
 --gen 8`` runs prefill on a synthetic prompt batch and decodes tokens,
-reporting per-phase timings.  Smoke scale on CPU; the same entry point
-targets the production mesh with ``--mesh single-pod``.
+reporting per-phase timings (compilation included).  Smoke scale by
+default; ``--no-smoke`` serves the published widths, and ``--mesh
+single-pod`` targets the production mesh.  ``chip_smoke.py`` drives the
+same :func:`generate` path on the chip.
 
 ``--continuous`` runs the model-guided continuous-batching engine
 (``repro.serve``) over a synthetic trace instead of a single static
@@ -38,6 +40,66 @@ def _continuous(args) -> int:
     return 0 if summary["lost"] == 0 else 1
 
 
+def init_params(arch, mesh, profile, seed: int):
+    """Materialise ``arch``'s parameters from ``seed`` in one jitted
+    program, each placed on ``mesh`` by ``profile``'s sharding rules."""
+    import jax
+
+    from repro.dist.sharding import param_shardings
+    from repro.models.common import materialize
+
+    spec = arch.param_spec()
+    return jax.jit(lambda k: materialize(spec, k),
+                   out_shardings=param_shardings(spec, mesh, profile))(
+        jax.random.key(seed))
+
+
+def serve_steps(arch, max_len: int):
+    """The serve path's jitted ``(prefill, decode)`` pair; build it once
+    and reuse it, so later calls of :func:`generate` compile nothing."""
+    import jax
+
+    prefill = jax.jit(lambda p, b: arch.prefill(p, b, max_len=max_len))
+    return prefill, jax.jit(arch.decode)
+
+
+def generate(arch, steps, params, batch, gen: int) -> dict:
+    """Prefill ``batch`` and greedily decode ``gen`` tokens.
+
+    Returns ``tokens`` (B, gen + 1): the argmax of the prefill, then of
+    each decode step (the first ``gen`` were fed back); ``logits``: the
+    prefill's last-position logits, then each decode step's, each
+    (B, 1, vocab_padded); and the wall seconds of both phases, each ended
+    by ``block_until_ready``.
+    """
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    prefill, decode = steps
+
+    def greedy(logits):
+        return jnp.argmax(logits[:, -1, : arch.cfg.vocab], -1)[:, None]
+
+    t0 = time.perf_counter()
+    logits, cache = prefill(params, batch)
+    jax.block_until_ready(logits)
+    t_prefill = time.perf_counter() - t0
+
+    all_logits = [logits]
+    toks = [greedy(logits).astype(jnp.int32)]
+    t0 = time.perf_counter()
+    for _ in range(gen):
+        logits, cache = decode(params, cache, {"tokens": toks[-1]})
+        all_logits.append(logits)
+        toks.append(greedy(logits).astype(jnp.int32))
+    jax.block_until_ready(toks[-1])
+    t_decode = time.perf_counter() - t0
+    return {"tokens": np.asarray(jnp.concatenate(toks, 1)),
+            "logits": all_logits, "prefill_s": t_prefill,
+            "decode_s": t_decode}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
@@ -62,18 +124,18 @@ def main() -> int:
     if args.continuous:
         return _continuous(args)
 
-    import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from repro.configs import ARCH_NAMES, get_arch
+    from repro.configs.base import ShapeSpec
     from repro.dist.sharding import get_profile, use_mesh_context
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh, make_production_mesh
-    from repro.models.common import materialize
 
     if args.arch not in ARCH_NAMES:
         ap.error(f"--arch must be one of {ARCH_NAMES}")
 
+    use_compile_cache()
     arch = get_arch(args.arch, smoke=args.smoke)
     if not arch.has_decoder:
         print(f"{arch.name}: encoder-only, nothing to serve")
@@ -84,41 +146,24 @@ def main() -> int:
     profile = get_profile(arch.profile, multi_pod=multi_pod)
     max_len = args.prompt_len + args.gen + 8
 
-    from repro.configs.base import ShapeSpec
     shape = ShapeSpec("cli_prefill", seq_len=args.prompt_len,
                       global_batch=args.batch, kind="prefill")
     batch = {k: jnp.asarray(v)
              for k, v in arch.make_batch(shape, seed=args.seed).items()}
 
     with use_mesh_context(mesh, profile, multi_pod=multi_pod):
-        params = materialize(arch.param_spec(), jax.random.key(args.seed))
-        prefill = jax.jit(lambda p, b: arch.prefill(p, b, max_len=max_len))
-        decode = jax.jit(arch.decode)
-
-        t0 = time.perf_counter()
-        logits, cache = prefill(params, batch)
-        jax.block_until_ready(logits)
-        t_prefill = time.perf_counter() - t0
-
-        toks = []
-        tok = jnp.argmax(logits[:, -1, : arch.cfg.vocab], -1)[:, None]
-        t0 = time.perf_counter()
-        for _ in range(args.gen):
-            logits, cache = decode(params, cache,
-                                   {"tokens": tok.astype(jnp.int32)})
-            tok = jnp.argmax(logits[:, -1, : arch.cfg.vocab], -1)[:, None]
-            toks.append(np.asarray(tok[:, 0]))
-        jax.block_until_ready(logits)
-        t_decode = time.perf_counter() - t0
+        params = init_params(arch, mesh, profile, args.seed)
+        out = generate(arch, serve_steps(arch, max_len), params, batch,
+                       args.gen)
 
     # --gen 0 is a prefill-only run: no decode steps happened, so a
     # per-token decode time does not exist (it is null, not 0/0)
     print(json.dumps({
         "arch": arch.name,
-        "prefill_s": round(t_prefill, 4),
-        "decode_s_per_tok": (round(t_decode / args.gen, 4)
+        "prefill_s": round(out["prefill_s"], 4),
+        "decode_s_per_tok": (round(out["decode_s"] / args.gen, 4)
                              if args.gen > 0 else None),
-        "tokens": np.stack(toks, 1).tolist() if toks else [],
+        "tokens": out["tokens"][:, 1:].tolist(),
     }, indent=1))
     return 0
 

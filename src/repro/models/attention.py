@@ -41,11 +41,20 @@ class AttnConfig:
 
 def attn_spec(cfg: AttnConfig) -> dict:
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    # fan-in is d for the input projections and h*hd for the output one;
+    # the default (the last-but-one dim: heads / head_dim) is up to 11x too
+    # wide at published widths, which makes every softmax one-hot and the
+    # logits chaotic in bf16 rounding
+    s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(h * hd)
     p = {
-        "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim")),
-        "wk": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
-        "wo": ParamSpec((h, hd, d), ("heads", "head_dim", "embed")),
+        "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim"),
+                        scale=s_in),
+        "wk": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim"),
+                        scale=s_in),
+        "wv": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim"),
+                        scale=s_in),
+        "wo": ParamSpec((h, hd, d), ("heads", "head_dim", "embed"),
+                        scale=s_out),
     }
     if cfg.qkv_bias:
         p["bq"] = ParamSpec((h, hd), ("heads", "head_dim"), init="zeros")
@@ -190,7 +199,6 @@ def _seq_sharded_cache_update(cache, new, length):
     in its range and writes locally via ``shard_map``; every other shard is
     a no-op.
     """
-    from jax.experimental.shard_map import shard_map
     from repro.dist.sharding import current_context
 
     ctx = current_context()
@@ -212,9 +220,9 @@ def _seq_sharded_cache_update(cache, new, length):
         return jax.lax.cond((idx >= 0) & (idx < s_loc), write, lambda c: c, c)
 
     P_ = P(batch_spec, seq_ax, None, None)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P_, P(batch_spec, None, None, None), P()),
-                     out_specs=P_, check_rep=False)(cache, new, length)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P_, P(batch_spec, None, None, None), P()),
+                         out_specs=P_, check_vma=False)(cache, new, length)
 
 
 def _update_cache(cache, new, length):
@@ -237,7 +245,6 @@ def _flash_decode(q, cache_k, cache_v, k_new, v_new, cache_len, *,
     the cache, computes local scores/max/sum/partial-out, and the softmax is
     completed with three tiny psums (max, denom, numerator).
     """
-    from jax.experimental.shard_map import shard_map
     from repro.dist.sharding import current_context
 
     ctx = current_context()
@@ -283,11 +290,11 @@ def _flash_decode(q, cache_k, cache_v, k_new, v_new, cache_len, *,
 
     Pc = P(bspec, seq_ax, None, None)
     Pq = P(bspec, None, None, None)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(Pq, Pc, Pc, Pq, Pq, P()),
-                     out_specs=(Pq, Pc, Pc),
-                     check_rep=False)(q, cache_k, cache_v, k_new, v_new,
-                                      cache_len)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(Pq, Pc, Pc, Pq, Pq, P()),
+                         out_specs=(Pq, Pc, Pc),
+                         check_vma=False)(q, cache_k, cache_v, k_new, v_new,
+                                          cache_len)
 
 
 def decode_attention(p, cfg: AttnConfig, x, cache_k, cache_v, cache_len):

@@ -96,64 +96,23 @@ def shard_annotate(x, axes: tuple[str | None, ...]):
     Divisibility-aware: an axis whose dimension does not divide by the mesh
     axes it maps to is left unsharded — uneven shardings make GSPMD pad and
     replicate (observed: 24 q-heads annotated onto a 16-way axis cost GiBs
-    of padded full-size copies in the minitron-4b dry-run).
+    of padded full-size copies in the minitron-4b dry-run).  Resolution is
+    ``dist.sharding.logical_to_pspec``'s.  The annotation is skipped when
+    the rules name an axis the active mesh lacks; any other error raises.
     """
     if _ACTIVATION_RULES is None:
         return x
-    from jax.sharding import PartitionSpec as P
+    from repro.dist.sharding import current_mesh, logical_to_pspec
 
-    assignment = [_ACTIVATION_RULES.get(a) if a else None for a in axes]
-    try:
-        from repro.dist.sharding import current_mesh
-        mesh = current_mesh()
-        if mesh is not None:
-            sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            checked = []
-            for dim, a in zip(x.shape, assignment):
-                if a is None:
-                    checked.append(None)
-                    continue
-                group = a if isinstance(a, tuple) else (a,)
-                # largest prefix of the group that divides the dim (matches
-                # dist.sharding.logical_to_pspec)
-                chosen = None
-                for k in range(len(group), 0, -1):
-                    n = 1
-                    for g in group[:k]:
-                        n *= sizes.get(g, 1)
-                    if n and dim % n == 0:
-                        chosen = group[:k] if k > 1 else group[0]
-                        break
-                checked.append(chosen)
-            assignment = checked
-        return jax.lax.with_sharding_constraint(x, P(*assignment))
-    except (KeyError, RuntimeError, TypeError, ValueError):
-        return x  # rules reference axes this mesh lacks: skip annotation
-
-
-# ---------------------------------------------------------------------------
-# Differentiable optimization barrier
-# ---------------------------------------------------------------------------
-
-
-@jax.custom_vjp
-def grad_barrier(x):
-    """``jax.lax.optimization_barrier`` with a gradient rule (the primitive
-    has none on this jax version).  The barrier is applied on both the
-    forward and the cotangent so XLA cannot hoist converts out of the
-    scan/backward loop in either direction."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _grad_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _grad_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-grad_barrier.defvjp(_grad_barrier_fwd, _grad_barrier_bwd)
+    mesh = current_mesh()
+    if mesh is None:
+        return x
+    pspec = logical_to_pspec(axes, _ACTIVATION_RULES, x.shape, mesh)
+    named = {g for e in pspec if e is not None
+             for g in (e if isinstance(e, tuple) else (e,))}
+    if not named <= set(mesh.axis_names):
+        return x
+    return jax.lax.with_sharding_constraint(x, pspec)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,6 @@ from .common import (
     ParamSpec,
     embed,
     embedding_spec,
-    grad_barrier,
     rmsnorm,
     rmsnorm_spec,
     shard_annotate,
@@ -141,8 +140,9 @@ def _layer_body(cfg: LMConfig):
     def body(h, p_l):
         # barrier: stops XLA from hoisting the rmsnorm bf16->f32 convert of
         # the *entire* saved-carry stack out of the backward while-loop
-        # (observed 2x carry-stack memory on the dry-run without it)
-        h = grad_barrier(h)
+        # (observed 2x carry-stack memory on the dry-run without it).  Its
+        # differentiation rule puts the same barrier on the cotangent.
+        h = jax.lax.optimization_barrier(h)
         a, _ = attention(p_l["attn"], cfg.attn_cfg,
                          rmsnorm(p_l["ln_attn"], h, cfg.norm_eps))
         h = h + a

@@ -147,7 +147,6 @@ def moe_ffn_shard_map(p, cfg: MoEConfig, x, *, mesh, data_axes=("data",),
     the collective cost is one psum of the (local-batch, d) output plus the
     FSDP weight all-gather, instead of GSPMD's inferred scatter traffic."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n_model = mesh.shape[model_axis]
     e = cfg.n_experts
@@ -198,7 +197,7 @@ def moe_ffn_shard_map(p, cfg: MoEConfig, x, *, mesh, data_axes=("data",),
         aux = jax.lax.pmean(aux, (*data_axes, model_axis))
         return out.reshape(bl, sl, d), aux
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(data_axes, None, None),
@@ -207,7 +206,7 @@ def moe_ffn_shard_map(p, cfg: MoEConfig, x, *, mesh, data_axes=("data",),
                   P(model_axis, fsdp_axis, None),
                   P(model_axis, fsdp_axis, None)),
         out_specs=(P(data_axes, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

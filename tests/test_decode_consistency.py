@@ -57,3 +57,34 @@ def test_whisper_decode_matches_teacher_forced():
         np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
                                    full[:, t], rtol=6e-2, atol=6e-2,
                                    err_msg=f"step {t}")
+
+
+def test_decode_matches_fresh_prefill_after_long_chunked_prompt():
+    """internlm2 as the full config runs it (chunked attention), at smoke
+    width and 4 layers: after a 512-token prompt each decode step's logits
+    match a fresh prefill over the prompt plus the tokens decoded so far,
+    within the bf16 bound chip_smoke.py holds the full width to.  A
+    softmax made one-hot by too wide an attention init fails this: the two
+    paths round q*scale at different points, which flips near-tied keys."""
+    import dataclasses
+
+    arch = get_arch("internlm2-1.8b", smoke=True)
+    cfg = dataclasses.replace(arch.cfg, attn_impl="chunked", n_layers=4)
+    arch = dataclasses.replace(arch, cfg=cfg)
+    params = materialize(arch.param_spec(), jax.random.key(0))
+    prompt = np.asarray(jax.random.randint(jax.random.key(1), (2, 512), 0,
+                                           cfg.vocab))
+    logits, cache = jax.jit(lambda p, b: arch.prefill(p, b, max_len=520))(
+        params, {"tokens": prompt})
+    fresh = jax.jit(lambda p, b: arch.prefill(p, b))
+    decode = jax.jit(arch.decode)
+    seq = prompt
+    for _ in range(4):
+        tok = np.asarray(jax.numpy.argmax(logits[:, -1, :cfg.vocab], -1),
+                         np.int32)[:, None]
+        seq = np.concatenate([seq, tok], 1)
+        logits, cache = decode(params, cache, {"tokens": tok})
+        want = np.asarray(fresh(params, {"tokens": seq})[0][:, 0, :cfg.vocab],
+                          np.float32)
+        got = np.asarray(logits[:, 0, :cfg.vocab], np.float32)
+        assert np.max(np.abs(got - want)) <= 2.0**-4 * np.max(np.abs(want))

@@ -68,11 +68,11 @@ def test_wire_bytes_ring_multipliers():
 def test_real_jax_lowering_collectives():
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     if len(jax.devices()) != 1:
         pytest.skip("expects the default single-device test env")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     f = lambda x: jnp.sum(x * 2.0)
     s = NamedSharding(mesh, P("data"))
     lowered = jax.jit(f, in_shardings=s).lower(
