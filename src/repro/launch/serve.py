@@ -59,8 +59,29 @@ def serve_steps(arch, max_len: int):
     and reuse it, so later calls of :func:`generate` compile nothing."""
     import jax
 
-    prefill = jax.jit(lambda p, b: arch.prefill(p, b, max_len=max_len))
-    return prefill, jax.jit(arch.decode)
+    def prefill(p, b):
+        return arch.prefill(p, b, max_len=max_len)
+
+    return jax.jit(prefill), jax.jit(arch.decode)
+
+
+class _Span:
+    """A host span: ``annotation`` (a ``TraceAnnotation``) opened, and
+    ``(name, start, end)`` on ``time.perf_counter`` appended to ``spans``
+    when it ends."""
+
+    __slots__ = ("spans", "name", "annotation", "start")
+
+    def __init__(self, spans: list, name: str, annotation):
+        self.spans, self.name, self.annotation = spans, name, annotation
+
+    def __enter__(self):
+        self.annotation.__enter__()
+        self.start = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.spans.append((self.name, self.start, time.perf_counter()))
+        self.annotation.__exit__(*exc)
 
 
 def generate(arch, steps, params, batch, gen: int) -> dict:
@@ -69,35 +90,57 @@ def generate(arch, steps, params, batch, gen: int) -> dict:
     Returns ``tokens`` (B, gen + 1): the argmax of the prefill, then of
     each decode step (the first ``gen`` were fed back); ``logits``: the
     prefill's last-position logits, then each decode step's, each
-    (B, 1, vocab_padded); and the wall seconds of both phases, each ended
-    by ``block_until_ready``.
+    (B, 1, vocab_padded); the wall seconds of both phases, each ended by
+    ``block_until_ready``; and ``spans``, the call's host spans as
+    ``(name, start, end)`` on ``time.perf_counter``, in order of start:
+
+    - ``serve.prefill``: prefill dispatched and its logits ready
+      (``prefill_s`` is its length);
+    - ``serve.sample``: the argmax of a step's logits dispatched, first
+      the prefill's, then one after each ``serve.decode_step``;
+    - ``serve.decode``: the decode loop, its last token ready
+      (``decode_s`` is its length); it holds
+    - ``serve.decode_step``: one decode step dispatched;
+    - ``serve.to_host``: the tokens joined and copied to the host.
+
+    Each span is also a ``jax.profiler.TraceAnnotation``, so that a
+    profiler trace shows it on the device's clock.
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     prefill, decode = steps
+    spans = []
+
+    def span(name):
+        return _Span(spans, name, jax.profiler.TraceAnnotation(name))
 
     def greedy(logits):
-        return jnp.argmax(logits[:, -1, : arch.cfg.vocab], -1)[:, None]
+        with span("serve.sample"):
+            return jnp.argmax(logits[:, -1, : arch.cfg.vocab],
+                              -1)[:, None].astype(jnp.int32)
 
-    t0 = time.perf_counter()
-    logits, cache = prefill(params, batch)
-    jax.block_until_ready(logits)
-    t_prefill = time.perf_counter() - t0
+    with span("serve.prefill"):
+        logits, cache = prefill(params, batch)
+        jax.block_until_ready(logits)
 
     all_logits = [logits]
-    toks = [greedy(logits).astype(jnp.int32)]
-    t0 = time.perf_counter()
-    for _ in range(gen):
-        logits, cache = decode(params, cache, {"tokens": toks[-1]})
-        all_logits.append(logits)
-        toks.append(greedy(logits).astype(jnp.int32))
-    jax.block_until_ready(toks[-1])
-    t_decode = time.perf_counter() - t0
-    return {"tokens": np.asarray(jnp.concatenate(toks, 1)),
-            "logits": all_logits, "prefill_s": t_prefill,
-            "decode_s": t_decode}
+    toks = [greedy(logits)]
+    with span("serve.decode"):
+        for _ in range(gen):
+            with span("serve.decode_step"):
+                logits, cache = decode(params, cache, {"tokens": toks[-1]})
+            all_logits.append(logits)
+            toks.append(greedy(logits))
+        jax.block_until_ready(toks[-1])
+    with span("serve.to_host"):
+        tokens = np.asarray(jnp.concatenate(toks, 1))
+    seconds = {name: end - start for name, start, end in spans}
+    return {"tokens": tokens, "logits": all_logits,
+            "prefill_s": seconds["serve.prefill"],
+            "decode_s": seconds["serve.decode"],
+            "spans": sorted(spans, key=lambda sp: sp[1])}
 
 
 def main() -> int:

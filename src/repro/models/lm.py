@@ -9,6 +9,12 @@ input per the brief).
 Layers are scanned (``lax.scan`` over parameters stacked on a leading
 "layers" axis) with configurable remat, so HLO size is O(1) in depth and
 94-layer configs compile quickly.
+
+Every step names its parts with ``jax.named_scope``: ``attn`` (attention
+norm, attention, and in decode the K/V cache slice and update), ``mlp``
+(FFN norm and FFN) and ``head`` (final norm and logits).  They add
+metadata (the compiled HLO's ``op_name``), not computation, so a profile
+can split device time by them.
 """
 from __future__ import annotations
 
@@ -143,10 +149,12 @@ def _layer_body(cfg: LMConfig):
         # (observed 2x carry-stack memory on the dry-run without it).  Its
         # differentiation rule puts the same barrier on the cotangent.
         h = jax.lax.optimization_barrier(h)
-        a, _ = attention(p_l["attn"], cfg.attn_cfg,
-                         rmsnorm(p_l["ln_attn"], h, cfg.norm_eps))
+        with jax.named_scope("attn"):
+            a, _ = attention(p_l["attn"], cfg.attn_cfg,
+                             rmsnorm(p_l["ln_attn"], h, cfg.norm_eps))
         h = h + a
-        f, aux = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], h, cfg.norm_eps))
+        with jax.named_scope("mlp"):
+            f, aux = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], h, cfg.norm_eps))
         h = h + f
         h = shard_annotate(h, ("batch", "seq", "embed"))
         return h, aux
@@ -179,7 +187,8 @@ def hidden_states(params, cfg: LMConfig, tokens, *, extra_embeds=None):
             step = _remat(body, cfg)
             h, a = step(h, params["layers"][f"layer_{i}"])
             aux = aux + a
-    return rmsnorm(params["ln_f"], h, cfg.norm_eps), aux
+    with jax.named_scope("head"):
+        return rmsnorm(params["ln_f"], h, cfg.norm_eps), aux
 
 
 def logits_fn(params, cfg: LMConfig, h):
@@ -194,7 +203,8 @@ def loss_fn(params, cfg: LMConfig, batch):
     (P + S_text) sequence."""
     h, aux = hidden_states(params, cfg, batch["tokens"],
                            extra_embeds=batch.get("patch_embeds"))
-    logits = logits_fn(params, cfg, h)
+    with jax.named_scope("head"):
+        logits = logits_fn(params, cfg, h)
     labels = batch["labels"]
     mask = batch.get("mask")
     loss = masked_xent(logits, labels, mask, cfg)
@@ -239,17 +249,20 @@ def prefill(params, cfg: LMConfig, batch, *, max_len: int | None = None):
     h = shard_annotate(h, ("batch", "seq", "embed"))
 
     def body(hh, p_l):
-        a, (k, v) = attention(p_l["attn"], cfg.attn_cfg,
-                              rmsnorm(p_l["ln_attn"], hh, cfg.norm_eps))
+        with jax.named_scope("attn"):
+            a, (k, v) = attention(p_l["attn"], cfg.attn_cfg,
+                                  rmsnorm(p_l["ln_attn"], hh, cfg.norm_eps))
         hh = hh + a
-        f, _ = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], hh, cfg.norm_eps))
+        with jax.named_scope("mlp"):
+            f, _ = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], hh, cfg.norm_eps))
         hh = hh + f
         hh = shard_annotate(hh, ("batch", "seq", "embed"))
         return hh, (k.astype(cfg.dtype), v.astype(cfg.dtype))
 
     h, (ks, vs) = jax.lax.scan(_remat(body, cfg), h, params["layers"])
-    h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-    logits = logits_fn(params, cfg, h[:, -1:, :])
+    with jax.named_scope("head"):
+        h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+        logits = logits_fn(params, cfg, h[:, -1:, :])
     s = tokens.shape[1] + (batch["patch_embeds"].shape[1]
                            if batch.get("patch_embeds") is not None else 0)
     if max_len is not None and max_len > s:
@@ -276,21 +289,24 @@ def decode_step(params, cfg: LMConfig, cache, batch):
     def body(carry, xs):
         hh, kc, vc = carry
         p_l, i = xs
-        ck = jax.lax.dynamic_index_in_dim(kc, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(vc, i, 0, keepdims=False)
-        a, ck, cv = decode_attention(
-            p_l["attn"], cfg.attn_cfg,
-            rmsnorm(p_l["ln_attn"], hh, cfg.norm_eps), ck, cv, length)
-        kc = jax.lax.dynamic_update_index_in_dim(kc, ck, i, 0)
-        vc = jax.lax.dynamic_update_index_in_dim(vc, cv, i, 0)
+        with jax.named_scope("attn"):
+            ck = jax.lax.dynamic_index_in_dim(kc, i, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(vc, i, 0, keepdims=False)
+            a, ck, cv = decode_attention(
+                p_l["attn"], cfg.attn_cfg,
+                rmsnorm(p_l["ln_attn"], hh, cfg.norm_eps), ck, cv, length)
+            kc = jax.lax.dynamic_update_index_in_dim(kc, ck, i, 0)
+            vc = jax.lax.dynamic_update_index_in_dim(vc, cv, i, 0)
         hh = hh + a
-        f, _ = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], hh, cfg.norm_eps))
+        with jax.named_scope("mlp"):
+            f, _ = _ffn(p_l, cfg, rmsnorm(p_l["ln_ffn"], hh, cfg.norm_eps))
         hh = hh + f
         return (hh, kc, vc), None
 
     (h, ks, vs), _ = jax.lax.scan(
         body, (h, cache["k"], cache["v"]),
         (params["layers"], jnp.arange(cfg.n_layers)))
-    h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
-    logits = logits_fn(params, cfg, h)
+    with jax.named_scope("head"):
+        h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
+        logits = logits_fn(params, cfg, h)
     return logits, {"k": ks, "v": vs, "length": length + 1}
