@@ -26,4 +26,5 @@ def lm_arch(name: str, cfg: lm.LMConfig, *, family: str = "dense",
         batch_spec_fn=batch_spec_fn,
         train_accum=train_accum,
         moment_dtype=moment_dtype,
+        serving_params_fn=lm.serving_params,
     )

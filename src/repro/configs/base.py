@@ -58,6 +58,11 @@ SHAPES: dict[str, ShapeSpec] = {
 # ---------------------------------------------------------------------------
 
 
+def stored_params(params, cfg):
+    """The default ``serving_params_fn``: serve the parameters as stored."""
+    return params
+
+
 @dataclass(frozen=True)
 class ArchDef:
     """One selectable architecture (``--arch <name>``)."""
@@ -84,6 +89,10 @@ class ArchDef:
     #: Adam moment storage for the production config (f32 | bf16 | int8);
     #: the HBM-footprint knob for the very large archs
     moment_dtype: str = "f32"
+    #: fn(params, cfg) -> the tree the serve path's prefill and decode
+    #: read, made once per ``generate`` call: the family's leaves that its
+    #: code casts to the compute dtype at every use, cast once
+    serving_params_fn: Callable = stored_params
 
     # -- parameters ----------------------------------------------------
     def param_spec(self):
@@ -119,6 +128,9 @@ class ArchDef:
 
     def decode(self, params, cache, batch):
         return self.decode_fn(params, self.cfg, cache, batch)
+
+    def serving_params(self, params):
+        return self.serving_params_fn(params, self.cfg)
 
     def cache_spec(self, batch_size: int, max_len: int):
         return self.cache_spec_fn(self.cfg, batch_size, max_len)

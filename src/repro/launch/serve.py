@@ -95,12 +95,16 @@ def generate(arch, steps, params, batch, gen: int) -> dict:
     ``(name, start, end)`` on ``time.perf_counter``, in order of start:
 
     - ``serve.prefill``: prefill dispatched and its logits ready
-      (``prefill_s`` is its length);
+      (``prefill_s`` is its length); it holds
+    - ``serve.weights``: ``arch.serving_params(params)`` dispatched, the
+      copy of the weights that the call's prefill and decode steps read,
+      made anew in every call (the caller keeps ``params``);
     - ``serve.sample``: the argmax of a step's logits dispatched, first
       the prefill's, then one after each ``serve.decode_step``;
     - ``serve.decode``: the decode loop, its last token ready
       (``decode_s`` is its length); it holds
-    - ``serve.decode_step``: one decode step dispatched;
+    - ``serve.decode_step``: the cache of the step before ready, then one
+      decode step dispatched;
     - ``serve.to_host``: the tokens joined and copied to the host.
 
     Each span is also a ``jax.profiler.TraceAnnotation``, so that a
@@ -122,7 +126,9 @@ def generate(arch, steps, params, batch, gen: int) -> dict:
                               -1)[:, None].astype(jnp.int32)
 
     with span("serve.prefill"):
-        logits, cache = prefill(params, batch)
+        with span("serve.weights"):
+            weights = arch.serving_params(params)
+        logits, cache = prefill(weights, batch)
         jax.block_until_ready(logits)
 
     all_logits = [logits]
@@ -130,7 +136,11 @@ def generate(arch, steps, params, batch, gen: int) -> dict:
     with span("serve.decode"):
         for _ in range(gen):
             with span("serve.decode_step"):
-                logits, cache = decode(params, cache, {"tokens": toks[-1]})
+                # one step in flight: a step's new cache is allocated as it
+                # is dispatched, so a host that ran ahead would hold one
+                # cache for every step it is ahead
+                jax.block_until_ready(cache)
+                logits, cache = decode(weights, cache, {"tokens": toks[-1]})
             all_logits.append(logits)
             toks.append(greedy(logits))
         jax.block_until_ready(toks[-1])

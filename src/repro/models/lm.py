@@ -19,6 +19,7 @@ can split device time by them.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
 import jax
@@ -232,6 +233,30 @@ def cache_spec(cfg: LMConfig, batch: int, max_len: int) -> dict:
         "v": ParamSpec(shape, axes, init="zeros", dtype=cfg.dtype),
         "length": ParamSpec((), (), init="zeros", dtype=jnp.int32),
     }
+
+
+def serving_params(params, cfg: LMConfig):
+    """The tree ``prefill`` and ``decode_step`` read in serving: every
+    weight they cast to ``cfg.dtype`` at use (attention, MLP and expert
+    weights, biases, norms, ``unembed``) cast once, in one jitted program,
+    so that each step reads 2 bytes a parameter and no longer converts
+    the stored 4.  The rounding is the same, so the steps' results are.
+
+    Left as stored: ``embedding``, of which a step gathers a few rows and
+    casts them (a copy of the whole table would cost more than it saves);
+    each MoE ``router`` goes to ``router_dtype``, in which its logits are
+    computed."""
+    served = {k: v for k, v in params.items() if k != "embedding"}
+    return {**_serving_cast(served, cfg), "embedding": params["embedding"]}
+
+
+@partial(jax.jit, static_argnums=1)
+def _serving_cast(params, cfg: LMConfig):
+    def cast(path, w):
+        if path[-1].key == "router":
+            return w.astype(cfg.moe.router_dtype)
+        return w.astype(cfg.dtype)
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 def prefill(params, cfg: LMConfig, batch, *, max_len: int | None = None):

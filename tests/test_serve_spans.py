@@ -56,7 +56,8 @@ def test_generate_returns_its_spans_in_order(served):
     arch, params, batch, steps = served
     out = generate(arch, steps, params, batch, GEN)
     names = [n for n, _, _ in out["spans"]]
-    assert names == (["serve.prefill", "serve.sample", "serve.decode"]
+    assert names == (["serve.prefill", "serve.weights", "serve.sample",
+                      "serve.decode"]
                      + ["serve.decode_step", "serve.sample"] * GEN
                      + ["serve.to_host"])
     starts = [s for _, s, _ in out["spans"]]
@@ -66,6 +67,41 @@ def test_generate_returns_its_spans_in_order(served):
     decode = by_name["serve.decode"]
     assert all(decode[0] <= s and e <= decode[1]
                for n, s, e in out["spans"] if n == "serve.decode_step")
+    prefill, weights = by_name["serve.prefill"], by_name["serve.weights"]
+    assert prefill[0] <= weights[0] and weights[1] <= prefill[1]
+
+
+def test_a_second_call_compiles_nothing(served):
+    """The serving copy's program, like the steps, is compiled once: a
+    call at the shapes of an earlier one compiles nothing."""
+    arch, params, batch, steps = served
+    generate(arch, steps, params, batch, GEN)
+    compiles = []
+
+    def listen(event, *_args, **_kw):
+        if "backend_compile" in event:
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        generate(arch, steps, params, batch, GEN)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
+def test_a_decode_step_waits_for_the_cache_it_takes(served):
+    """``generate`` dispatches a decode step only once the cache it takes
+    is ready: each step's new cache is allocated as it is dispatched, so
+    no more than two caches are alive however far the host could run
+    ahead."""
+    arch, params, batch, (prefill, decode) = served
+    ready = []
+
+    def checked(p, cache, b):
+        ready.append(all(x.is_ready() for x in jax.tree.leaves(cache)))
+        return decode(p, cache, b)
+    generate(arch, (prefill, checked), params, batch, GEN)
+    assert ready == [True] * GEN
 
 
 def test_phase_seconds_are_their_spans(served):
@@ -85,6 +121,7 @@ def test_spans_reach_the_profilers_trace(served, tmp_path):
     (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
     names = [e.name for p in ProfileData.from_file(path).planes
              for line in p.lines for e in line.events]
+    assert names.count("serve.weights") == 1
     assert names.count("serve.prefill") == 1
     assert names.count("serve.decode_step") == GEN
     assert names.count("serve.to_host") == 1

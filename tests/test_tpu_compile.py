@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import: only
 the one test worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,11 +105,10 @@ def test_flash_attention_internlm2_prefill_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("step", ["prefill", "decode"])
-def test_internlm2_serve_step_fits_one_chip(one_chip, step):
-    """The full-width serve steps of ``chip_smoke.py`` (4 requests, 512
-    prompt tokens, 16 decoded) compile, and their arguments plus
-    temporaries fit one chip's HBM."""
+def _serve_step(one_chip, step, weights=lambda arch, stored: stored):
+    """A full-width serve step of ``chip_smoke.py`` (4 requests, 512 prompt
+    tokens, 16 decoded), compiled for one chip, with ``weights`` made
+    from the stored parameters' shapes; also returns those shapes."""
     arch = get_arch("internlm2-1.8b", smoke=False)
     batch, prompt, max_len = 4, 512, 512 + 16 + 8
 
@@ -116,20 +116,45 @@ def test_internlm2_serve_step_fits_one_chip(one_chip, step):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=one_chip), tree)
 
-    params = place(abstract(arch.param_spec()))
+    stored = place(abstract(arch.param_spec()))
+    params = place(weights(arch, stored))
     if step == "prefill":
         tokens = jax.ShapeDtypeStruct((batch, prompt), jnp.int32,
                                       sharding=one_chip)
-        c = _compile(lambda p, t: arch.prefill(p, {"tokens": t},
-                                               max_len=max_len),
-                     params, tokens)
-    else:
-        cache = place(abstract(arch.cache_spec(batch, max_len)))
-        tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32,
-                                      sharding=one_chip)
-        c = _compile(lambda p, kv, t: arch.decode(p, kv, {"tokens": t}),
-                     params, cache, tokens)
+        return _compile(lambda p, t: arch.prefill(p, {"tokens": t},
+                                                  max_len=max_len),
+                        params, tokens), stored
+    cache = place(abstract(arch.cache_spec(batch, max_len)))
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    return _compile(lambda p, kv, t: arch.decode(p, kv, {"tokens": t}),
+                    params, cache, tokens), stored
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_internlm2_serve_step_fits_one_chip(one_chip, step):
+    """The full-width serve steps compile, and their arguments plus
+    temporaries fit one chip's HBM."""
+    c, _ = _serve_step(one_chip, step)
     mem = c.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 7e9 < mem.argument_size_in_bytes          # f32 weights: real
+    assert used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_internlm2_serve_step_on_the_serving_copy(one_chip, step):
+    """Fed ``arch.serving_params``' bf16 copy, as ``generate`` feeds them,
+    the full-width serve steps convert no stack of weights, and the stored
+    f32 tree, the copy and the step's temporaries fit one chip."""
+    c, stored = _serve_step(
+        one_chip, step,
+        lambda arch, stored: jax.eval_shape(arch.serving_params, stored))
+    n_layers = get_arch("internlm2-1.8b", smoke=False).cfg.n_layers
+    assert not re.search(rf"= bf16\[{n_layers},\S* convert\(", c.as_text())
+    mem = c.memory_analysis()
+    stored_bytes = sum(s.size * s.dtype.itemsize
+                       for s in jax.tree.leaves(stored))
+    assert mem.argument_size_in_bytes < stored_bytes / 1.5
+    used = (stored_bytes + mem.argument_size_in_bytes
+            + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, used
